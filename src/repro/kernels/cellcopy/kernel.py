@@ -24,44 +24,57 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 LANE = 128
 
 
 def _cellcopy_body(src_ref, dst_ref, sum_ref):
     """One grid step: copy `block_cells` cells and emit their checksums."""
-    data = src_ref[...]                       # (block_cells, words) int32
-    dst_ref[...] = data
-    # wrapping u32 sum per cell — the validity word the consumer checks
-    s = jnp.sum(data.astype(jnp.uint32), axis=1, dtype=jnp.uint32)
-    sum_ref[...] = s
+    data = src_ref[0]                         # (block_cells, words) int32
+    dst_ref[0] = data
+    # wrapping 32-bit sum per cell — the validity word the consumer checks.
+    # Mosaic reduces no unsigned integers; an int32 sum wraps to the same
+    # bits, and the wrapper bitcasts it to uint32.
+    s = jnp.sum(data, axis=1, dtype=jnp.int32)          # (block_cells,)
+    sum_ref[...] = s.reshape(sum_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("block_cells", "interpret"))
 def cellcopy(src: jax.Array, *, block_cells: int = 8,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """Copy (n_cells, words) int32 cells; returns (dst, checksums u32).
 
     ``block_cells`` cells ride one VMEM block per grid step; the Pallas
     pipeline double-buffers the HBM->VMEM->HBM stream across steps.
+    ``interpret=None`` compiles on a TPU and interprets elsewhere.
+
+    The kernel sees the cells as (n_blocks, block_cells, words) and the
+    checksums as (n_blocks, 1, block_cells): each block's last two dims
+    equal the array's, which Mosaic accepts for any ``block_cells`` (a
+    (block_cells, words) block of the flat array needs a multiple of 8,
+    and a rank-1 (block_cells,) block a multiple of 128).
     """
     n_cells, words = src.shape
     assert n_cells % block_cells == 0, (n_cells, block_cells)
     assert words % LANE == 0, f"cell words {words} not {LANE}-aligned"
-    grid = (n_cells // block_cells,)
-    return pl.pallas_call(
+    n_blocks = n_cells // block_cells
+    dst, sums = pl.pallas_call(
         _cellcopy_body,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_cells, words), lambda i: (i, 0))],
+        grid=(n_blocks,),
+        in_specs=[pl.BlockSpec((1, block_cells, words), lambda i: (i, 0, 0))],
         out_specs=[
-            pl.BlockSpec((block_cells, words), lambda i: (i, 0)),
-            pl.BlockSpec((block_cells,), lambda i: (i,)),
+            pl.BlockSpec((1, block_cells, words), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, block_cells), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_cells, words), src.dtype),
-            jax.ShapeDtypeStruct((n_cells,), jnp.uint32),
+            jax.ShapeDtypeStruct((n_blocks, block_cells, words), src.dtype),
+            jax.ShapeDtypeStruct((n_blocks, 1, block_cells), jnp.int32),
         ],
-        interpret=interpret,
-    )(src)
+        interpret=interpret_mode(interpret),
+    )(src.reshape(n_blocks, block_cells, words))
+    return (dst.reshape(n_cells, words),
+            jax.lax.bitcast_convert_type(sums.reshape(n_cells), jnp.uint32))
 
 
 def vmem_bytes(block_cells: int, words: int) -> int:

@@ -10,7 +10,7 @@ from repro.kernels.cellcopy.kernel import LANE, cellcopy
 
 
 def copy_message(buf: np.ndarray | jax.Array, cell_bytes: int = 16384, *,
-                 block_cells: int = 8, interpret: bool = True):
+                 block_cells: int = 8, interpret: bool | None = None):
     """Copy a flat uint8 message through cell-granular kernel DMA.
 
     Returns (copied uint8 array of the original length, checksums)."""

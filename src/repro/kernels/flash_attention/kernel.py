@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -74,9 +76,11 @@ def _flash_body(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jax.Array:
+                    block_k: int = 128,
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B, H, S, D); k, v: (B, KV, S, D) with H % KV == 0.
-    Returns (B, H, S, D) in q.dtype."""
+    Returns (B, H, S, D) in q.dtype. ``interpret=None`` compiles on a TPU
+    and interprets elsewhere."""
     b, h, s, d = q.shape
     kv = k.shape[1]
     assert h % kv == 0, (h, kv)
@@ -108,5 +112,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

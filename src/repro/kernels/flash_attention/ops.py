@@ -16,8 +16,6 @@ def flash_attention_bshd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          block_k: int = 128,
                          interpret: bool | None = None) -> jax.Array:
     """q: (B, S, H, D); k, v: (B, S, KV, D) — the blocks.py layout."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)

@@ -15,9 +15,10 @@ chunk of L tokens into dense L x n / n x n matmuls (MXU food):
     S_L   = diag(P_L) S_0 + sum_i (P_L / P_i * k_i) v_i^T
 
 Grid (B, H, n_chunks): the chunk axis is innermost/sequential, so the f32
-state S rides in VMEM scratch across chunk steps — the standard Pallas
-carry pattern. L is kept small (32) so the decay ratios P/P_i stay in f32
-range (w in (0,1); worst case w^-L).
+state S (stored transposed) rides in VMEM scratch across chunk steps — the
+standard Pallas carry pattern. P comes from a cumulative sum of log w
+(a triangular matmul: Mosaic lowers no cumprod). L is kept small (32) so
+the decay ratios P/P_i stay in f32 range (w in (0,1); worst case w^-L).
 
 All math f32; inputs (r, k, v, w) are pre-projected (B, H, S, n) tensors.
 """
@@ -30,6 +31,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
+
+def _dot(a, b, contract):
+    """f32 matmul at full precision (the default may round to bf16)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
 
 def _wkv6_body(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, L: int):
     ci = pl.program_id(2)
@@ -41,44 +51,43 @@ def _wkv6_body(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, L: int):
     r = r_ref[0, 0].astype(jnp.float32)       # (L, n)
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
-    w = w_ref[0, 0].astype(jnp.float32)       # decay in (0, 1)
-    u = u_ref[0].astype(jnp.float32)          # (n,)
-    S0 = s_ref[...]                           # (n, n)
+    logw = jnp.log(w_ref[0, 0].astype(jnp.float32))   # decay in (0, 1)
+    u = u_ref[0].astype(jnp.float32)          # (1, n)
+    St = s_ref[...]                           # (n, n) = S0^T: [value, key]
 
-    P = jnp.cumprod(w, axis=0)                # (L, n): prod_{j<=t} w_j
-    Pprev = jnp.concatenate([jnp.ones((1, P.shape[1]), jnp.float32),
-                             P[:-1]], axis=0)            # prod_{j<t}
-
-    rP = r * Pprev                            # (L, n)
-    # inter-chunk: (r_t * P_{t-1}) @ S0
-    o = jax.lax.dot_general(rP, S0, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    # intra-chunk: att[t, i] = sum_c rP[t,c] * (k[i,c] / P[i,c]),  i < t
-    kP = k / P
-    att = jax.lax.dot_general(rP, kP, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # (L, L)
     ti = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     ij = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # log P_t = sum_{j<=t} log w_j as a lower-triangular matmul (Mosaic
+    # lowers no cumprod/cumsum); log P_{t-1} = log P_t - log w_t
+    logP = _dot(jnp.where(ij <= ti, 1.0, 0.0), logw, ((1,), (0,)))
+    rP = r * jnp.exp(logP - logw)             # r_t * P_{t-1}
+    # inter-chunk: (r_t * P_{t-1}) @ S0
+    o = _dot(rP, St, ((1,), (1,)))
+    # intra-chunk: att[t, i] = sum_c rP[t,c] * (k[i,c] / P[i,c]),  i < t
+    att = _dot(rP, k * jnp.exp(-logP), ((1,), (1,)))    # (L, L)
     att = jnp.where(ij < ti, att, 0.0)        # strictly lower triangular
-    o = o + jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    o = o + _dot(att, v, ((1,), (0,)))
     # current token bonus: (r_t * u . k_t) v_t
-    o = o + jnp.sum(r * u[None, :] * k, axis=1, keepdims=True) * v
+    o = o + jnp.sum(r * u * k, axis=1, keepdims=True) * v
     o_ref[0, 0] = o.astype(o_ref.dtype)
 
-    # state update: S_L = diag(P_L) S0 + sum_i ((P_L / P_i) * k_i) v_i^T
-    kS = (P[-1][None, :] / P) * k             # (L, n)
-    s_new = P[-1][:, None] * S0 + jax.lax.dot_general(
-        kS, v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)   # (n, n)
-    s_ref[...] = s_new
+    # state update: S_L = diag(P_L) S0 + sum_i ((P_L / P_i) * k_i) v_i^T,
+    # kept transposed so diag(P_L) scales lanes and needs no relayout
+    logPL = logP[L - 1:L]                     # (1, n)
+    kS = jnp.exp(logPL - logP) * k            # (L, n)
+    s_ref[...] = jnp.exp(logPL) * St + _dot(v, kS, ((0,), (0,)))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
          u: jax.Array, *, chunk: int = 32,
-         interpret: bool = True) -> jax.Array:
-    """r,k,v,w: (B, H, S, n); u: (H, n). Returns (B, H, S, n) f32."""
+         interpret: bool | None = None) -> jax.Array:
+    """r,k,v,w: (B, H, S, n); u: (H, n). Returns (B, H, S, n) f32.
+
+    ``u`` enters the kernel as (H, 1, n) so that its (1, 1, n) block ends
+    in the array's own last two dims, as Mosaic requires (a (1, n) block
+    of an (H, n) array is refused). ``interpret=None`` compiles on a TPU
+    and interprets elsewhere."""
     b, h, s, n = r.shape
     L = min(chunk, s)
     assert s % L == 0, (s, L)
@@ -92,10 +101,10 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             pl.BlockSpec((1, 1, L, n), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, L, n), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, L, n), lambda b_, h_, c: (b_, h_, c, 0)),
-            pl.BlockSpec((1, n), lambda b_, h_, c: (h_, 0)),
+            pl.BlockSpec((1, 1, n), lambda b_, h_, c: (h_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, L, n), lambda b_, h_, c: (b_, h_, c, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        interpret=interpret,
-    )(r, k, v, w, u)
+        interpret=interpret_mode(interpret),
+    )(r, k, v, w, u.reshape(h, 1, n))

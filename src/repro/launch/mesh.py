@@ -31,7 +31,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU tests (requires forced host device count)."""
+    """Small mesh over the first devices: a forced host device count on
+    the CPU, or real chips (``chip_smoke.py --four-chips`` puts (2, 2)
+    and (4,) meshes on a four-chip host)."""
     n = 1
     for s in shape:
         n *= s

@@ -25,22 +25,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 
 
-def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
-                seed: int = 0, greedy: bool = True, quiet: bool = False
-                ) -> dict:
-    """Prefill a batch of prompts, then decode `gen` tokens each."""
-    params = lm.init(cfg, jax.random.key(seed))
-    rng = np.random.default_rng(seed)
-    cache_len = prompt_len + gen
-
-    prompts = rng.integers(0, cfg.vocab_size, size=(batch, prompt_len),
-                           dtype=np.int32)
-
-    state = lm.decode_state_init(cfg, batch, cache_len)
-
+def make_decode_fn(cfg):
+    """The jitted one-token serving step: (params, state, tok (B, 1),
+    pos (B,)) -> (logits (B, vocab), state)."""
     @jax.jit
     def decode_fn(params, state, tok, pos):
         b = {"tokens": tok}
@@ -49,32 +40,77 @@ def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
             b = {"frames": emb[tok[:, 0]][:, None, :]}
         return lm.decode_step(params, cfg, state, b, pos)
 
+    return decode_fn
+
+
+def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
+                seed: int = 0, greedy: bool = True, quiet: bool = False,
+                params=None, keep_logits: int = 0) -> dict:
+    """Prefill a batch of prompts, then decode `gen` tokens each.
+
+    ``params`` defaults to ``lm.init`` from ``seed``. The first call of the
+    step (trace, compile and one step) is timed as ``compile_s`` before the
+    prefill clock starts; both clocks stop on ``block_until_ready``, so
+    ``prefill_s`` and ``decode_s`` time device work, not the enqueue.
+    ``keep_logits`` returns the logits each of the first that many
+    generated tokens was picked from (as device arrays, under "logits").
+    """
+    if params is None:
+        params = jax.jit(lm.init, static_argnums=0)(cfg, jax.random.key(seed))
+    rng = np.random.default_rng(seed)
+    cache_len = prompt_len + gen
+
+    prompts = rng.integers(0, cfg.vocab_size, size=(batch, prompt_len),
+                           dtype=np.int32)
+
+    state = lm.decode_state_init(cfg, batch, cache_len)
+    decode_fn = make_decode_fn(cfg)
+
+    @jax.jit
+    def pick(logits, j):
+        if greedy:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jax.random.categorical(jax.random.key(j),
+                                      logits).astype(jnp.int32)
+
+    def pos_at(i):
+        return jnp.full((batch,), i, jnp.int32)
+
+    t0 = time.perf_counter()
+    warm, _ = decode_fn(params, state, jnp.asarray(prompts[:, :1]), pos_at(0))
+    jax.block_until_ready(pick(warm, 0))
+    t_compile = time.perf_counter() - t0
+
     # prefill via decode steps (teacher-forcing the prompt) — exercises the
     # cache write path end to end; a fused prefill kernel is the TPU path.
     t0 = time.perf_counter()
     logits = None
     for i in range(prompt_len):
         tok = jnp.asarray(prompts[:, i:i + 1])
-        pos = jnp.full((batch,), i, jnp.int32)
-        logits, state = decode_fn(params, state, tok, pos)
+        logits, state = decode_fn(params, state, tok, pos_at(i))
+    jax.block_until_ready(logits)
     t_prefill = time.perf_counter() - t0
 
     out_tokens = np.zeros((batch, gen), np.int32)
+    kept = []
     t0 = time.perf_counter()
     for j in range(gen):
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32) if greedy else \
-            jax.random.categorical(jax.random.key(j), logits).astype(jnp.int32)
+        if j < keep_logits:
+            kept.append(logits)
+        nxt = pick(logits, j)
         out_tokens[:, j] = np.asarray(nxt)
-        pos = jnp.full((batch,), prompt_len + j, jnp.int32)
-        logits, state = decode_fn(params, state, nxt[:, None], pos)
+        logits, state = decode_fn(params, state, nxt[:, None],
+                                  pos_at(prompt_len + j))
+    jax.block_until_ready(logits)
     t_decode = time.perf_counter() - t0
 
     tput = batch * gen / max(t_decode, 1e-9)
     if not quiet:
-        print(f"[serve] batch={batch} prefill {prompt_len} tok in "
-              f"{t_prefill:.2f}s | decode {gen} tok in {t_decode:.2f}s "
-              f"({tput:.1f} tok/s)")
-    return {"tokens": out_tokens, "decode_tok_per_s": tput,
+        print(f"[serve] batch={batch} compile {t_compile:.2f}s | prefill "
+              f"{prompt_len} tok in {t_prefill:.2f}s | decode {gen} tok in "
+              f"{t_decode:.2f}s ({tput:.1f} tok/s)")
+    return {"tokens": out_tokens, "prompts": prompts, "logits": kept,
+            "decode_tok_per_s": tput, "compile_s": t_compile,
             "prefill_s": t_prefill, "decode_s": t_decode}
 
 
@@ -112,6 +148,7 @@ def main() -> None:
     ap.add_argument("--rate", type=float, default=400.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.ranks > 1:
         serve_distributed(ranks=args.ranks, sessions=args.sessions,
                           rate=args.rate, seed=args.seed)

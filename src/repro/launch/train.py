@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import SHAPES, get_config
 from repro.configs.base import InputShape
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.train import data as D
 from repro.train import optimizer as opt
@@ -109,6 +110,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     shape = SHAPES[args.shape]

@@ -2,6 +2,7 @@
 XLA_FLAGS=--xla_force_host_platform_device_count=8 (the main pytest process
 keeps the real single-device view, per the assignment)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -13,7 +14,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-SRC = str(Path(__file__).resolve().parent.parent / "src")
+ROOT = Path(__file__).resolve().parent.parent
+SRC = str(ROOT / "src")
 
 
 def run_sub(code: str) -> dict:
@@ -21,9 +23,13 @@ def run_sub(code: str) -> dict:
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n"
             + textwrap.dedent(code))
+    # the children need only the host's CPU: say so, or on a TPU host they
+    # would try to open the chip this process may hold
+    env = {"PYTHONPATH": os.pathsep.join([SRC, str(ROOT)]),
+           "PATH": "/usr/bin:/bin", "HOME": os.path.expanduser("~"),
+           "JAX_PLATFORMS": "cpu"}
     out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-                                          "HOME": "/root"}, timeout=540)
+                         text=True, env=env, timeout=540)
     assert out.returncode == 0, f"stderr:\n{out.stderr[-3000:]}"
     return json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -227,6 +233,22 @@ def test_flashdecode_matches_auto():
     assert res["maxdiff"] < 1e-4
 
 
+def test_chip_smoke_grad_sync_phase_on_host_devices():
+    """chip_smoke.py --four-chips's phase at tiny widths on four host
+    devices: hierarchical plain and int8 sync_grads each agree with the
+    flat all-reduce within the script's own bounds."""
+    res = run_sub("""
+        import json
+        import chip_smoke
+        from repro.configs import get_config
+        chip_smoke.grad_sync_phase(get_config("smollm-135m").reduced(),
+                                   seq_len=32, global_batch=8, steps=2,
+                                   seed=0)
+        print(json.dumps({"ok": True}))
+    """)
+    assert res["ok"]
+
+
 def test_compression_roundtrip_bounds():
     from repro.distributed import compression as C
     rng = np.random.default_rng(0)
@@ -247,6 +269,21 @@ def test_compression_roundtrip_bounds():
         total = total + dec
     # accumulated decode ~= 4x the true signal (residual carried)
     assert float(jnp.abs(total / 4 - x).max()) < float(np.asarray(s).max())
+
+
+def test_psum_int8_shares_one_scale():
+    """Members whose shards span different ranges still sum to the true
+    sum, off by at most half a quantization step (of the largest range)
+    per member."""
+    from repro.distributed import compression as C
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(2, 4096)).astype(np.float32)
+    x[1] *= 0.5
+    got = jax.vmap(lambda a: C.psum_int8(a, "pod"), axis_name="pod")(
+        jnp.asarray(x))
+    step = np.abs(x).max() / 127.0
+    err = np.abs(np.asarray(got) - x.sum(0)[None])
+    assert (err <= 2 * step / 2 + 1e-6).all(), err.max() / step
 
 
 def test_sharding_rules_cover_all_archs():

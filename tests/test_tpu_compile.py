@@ -1,0 +1,78 @@
+"""Compile the Pallas kernels at real widths for a described TPU v5e chip.
+
+Nothing runs: the TPU compiler that ships with jaxlib lowers each kernel
+through Mosaic for a ``v5e:2x2`` topology that is described, not attached,
+and refuses what the chip would refuse (block shapes off the (8, 128)
+tiling, primitives Mosaic cannot lower, too much VMEM) — all of which the
+interpret-mode tests in test_kernels.py cannot see. The topology is built
+in a module fixture, never at import, so every xdist worker collects the
+same tests and only the worker that runs this file loads libtpu.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.cellcopy.kernel import cellcopy
+from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.rwkv6.kernel import wkv6
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2 host, with the persistent
+    compilation cache off: a TPU executable written here could not be
+    read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # keep libtpu from writing log files outside the checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # a Mosaic kernel
+    return compiled
+
+
+@pytest.mark.parametrize("block_cells", [1, 8, 16])
+def test_cellcopy_64k_cells_compiles(one_chip, block_cells):
+    """Paper Fig 9's 64 KiB cells, 16 MiB message; the checksum layout
+    compiles for any block_cells, including ones off the 8-row tiling."""
+    _compile(lambda x: cellcopy(x, block_cells=block_cells,
+                                interpret=False),
+             one_chip, ((256, 64 * 1024 // 4), jnp.int32))
+
+
+def test_flash_attention_glm4_heads_compiles(one_chip):
+    """glm4-9b heads: 32 query heads over 2 KV heads of 128, S=4096."""
+    b, h, kv, s, d = 1, 32, 2, 4096, 128
+    _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False),
+             one_chip, ((b, h, s, d), jnp.bfloat16),
+             ((b, kv, s, d), jnp.bfloat16), ((b, kv, s, d), jnp.bfloat16))
+
+
+def test_wkv6_rwkv6_3b_heads_compiles(one_chip):
+    """rwkv6-3b: 40 heads of 64, S=4096 in chunks of 32."""
+    b, h, s, n = 1, 40, 4096, 64
+    x = ((b, h, s, n), jnp.float32)
+    _compile(lambda r, k, v, w, u: wkv6(r, k, v, w, u, interpret=False),
+             one_chip, x, x, x, x, ((h, n), jnp.float32))
