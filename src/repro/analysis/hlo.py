@@ -427,6 +427,127 @@ def analyze_module(text: str) -> ModuleStats:
 
 
 # --------------------------------------------------------------------------
+# op -> named scope
+# --------------------------------------------------------------------------
+
+UNSCOPED = "unscoped"
+_COPIES = {"copy", "copy-start", "copy-done", "bitcast"}
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_METADATA_RE = re.compile(r", metadata=\{[^}]*\}")
+_SOURCE_TABLES = {"FileNames", "FunctionNames", "FileLocations", "StackFrames"}
+
+
+def scope_of(op_name: str, scopes, cast: str) -> str | None:
+    """The innermost of ``scopes`` in an ``op_name`` path such as
+    ``jit(f)/while/body/closed_call/moe/experts/cast/convert_element_type``,
+    or ``cast`` where that segment is in it; None where neither is. The
+    path's last segment names the primitive and is not a scope."""
+    segs = op_name.split("/")[:-1]
+    if cast in segs:
+        return cast
+    best, end = None, -1
+    for sc in scopes:
+        parts = sc.split("/")
+        for i in range(len(segs) - len(parts) + 1):
+            if segs[i:i + len(parts)] == parts and i + len(parts) > end:
+                best, end = sc, i + len(parts)
+    return best
+
+
+def op_scopes(hlo_text: str) -> dict[str, str]:
+    """Instruction name (``fusion.192``, ``convert.82``, ...) -> the named
+    scope of ``repro.models.blocks.SCOPES`` it was traced in, ``cast``
+    for a weight's conversion, or ``unscoped``; for every instruction of
+    an optimized HLO text (``jitted.lower(...).compile().as_text()``).
+
+    An instruction's scope is the innermost known one in its
+    ``metadata={op_name=...}``. XLA gives a fusion the op_name of its
+    root, so a fusion that merges a weight's cast into the matmul that
+    consumes it is charged to that matmul's sublayer, not to ``cast``:
+    the cast costs no pass of its own there. Where XLA left an
+    instruction without a known scope:
+
+    - a fusion with no op_name (a multi-output fusion, whose root is a
+      tuple) takes its root's scope, else the one most of its fused
+      instructions carry;
+    - a conversion that XLA hoisted out of the layer loop, of a weight
+      (an entry parameter of the program's ``params`` argument, or a
+      prefetched copy of one), is ``cast``;
+    - any other takes the scope of the instructions that feed it, where
+      those with a scope agree (a conversion or layout copy XLA split off
+      its producer).
+    """
+    from repro.models.blocks import CAST, SCOPES
+    comps = _split_computations(hlo_text)
+    ops = {i.name: i for c in comps.values() for i in c.instrs}
+    entry = next((c for c in comps.values() if c.is_entry), None)
+    entry_ops = {i.name: i for i in entry.instrs} if entry is not None else {}
+    memo: dict[str, str | None] = {}
+
+    def weight(name: str) -> bool:
+        ins = entry_ops.get(name)
+        while ins is not None and ins.op in _COPIES and ins.operands:
+            ins = entry_ops.get(ins.operands[0])
+        return (ins is not None and ins.op == "parameter"
+                and ins.name.startswith("params"))
+
+    def fused(ins):
+        m = re.search(r"calls=%?([\w\.\-]+)", ins.line)
+        c = comps.get(m.group(1)) if ins.op == "fusion" and m else None
+        return c if c is not None and c.instrs else None
+
+    def root(c):
+        return next((i for i in c.instrs
+                     if i.line.lstrip().startswith("ROOT")), c.instrs[-1])
+
+    def own(ins) -> str | None:
+        m = _OP_NAME_RE.search(ins.line)
+        return scope_of(m.group(1), SCOPES, CAST) if m else None
+
+    def of(ins) -> str | None:
+        if ins.name in memo:
+            return memo[ins.name]
+        memo[ins.name] = None            # a cycle reads as no scope
+        sc = own(ins)
+        callee = fused(ins)
+        if sc is None and callee is not None:
+            sc = of(root(callee))
+            if sc is None:
+                votes = [v for v in map(of, callee.instrs) if v is not None]
+                if votes:
+                    sc = max(set(votes), key=votes.count)
+        converts = ins.op == "convert" or (
+            callee is not None and root(callee).op == "convert")
+        if (sc is None and converts and ins.operands
+                and all(weight(o) for o in ins.operands)):
+            sc = CAST
+        if sc is None:
+            fed = {of(ops[o]) for o in ins.operands if o in ops} - {None}
+            if len(fed) == 1:
+                sc = fed.pop()
+        memo[ins.name] = sc
+        return sc
+
+    return {name: of(ins) or UNSCOPED for name, ins in ops.items()}
+
+
+def strip_metadata(hlo_text: str) -> str:
+    """An HLO text without its source metadata (each instruction's
+    ``metadata={...}`` and the module's file, function and stack-frame
+    tables): what named scopes change, and nothing else."""
+    out, tables = [], False
+    for line in hlo_text.splitlines():
+        if line in _SOURCE_TABLES:
+            tables = True
+            continue
+        if tables and line.startswith(("%", "ENTRY")):
+            tables = False
+        if not tables:
+            out.append(_METADATA_RE.sub("", line))
+    return "\n".join(out)
+
+
+# --------------------------------------------------------------------------
 # roofline
 # --------------------------------------------------------------------------
 

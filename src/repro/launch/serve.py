@@ -26,7 +26,14 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models import lm
+from repro.models import blocks, lm
+
+# The host spans of a served batch, as ``jax.profiler.TraceAnnotation``
+# names: admission, the prompt's steps, each generated token's step, and
+# the read of each token to the host. They share a profile's clock with
+# the device's ops, so a profile names each idle gap of the device by
+# what the host was doing in it.
+SPANS = ("batch_admit", "prefill", "decode", "host_read")
 
 
 def make_decode_fn(cfg):
@@ -36,8 +43,10 @@ def make_decode_fn(cfg):
     def decode_fn(params, state, tok, pos):
         b = {"tokens": tok}
         if cfg.frontend == "frames":
-            emb = params["embed"].astype(jnp.dtype(cfg.compute_dtype))
-            b = {"frames": emb[tok[:, 0]][:, None, :]}
+            with blocks.scope("embed"):
+                emb = blocks.cast(params["embed"],
+                                  jnp.dtype(cfg.compute_dtype))
+                b = {"frames": emb[tok[:, 0]][:, None, :]}
         return lm.decode_step(params, cfg, state, b, pos)
 
     return decode_fn
@@ -59,11 +68,13 @@ def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
         params = jax.jit(lm.init, static_argnums=0)(cfg, jax.random.key(seed))
     rng = np.random.default_rng(seed)
     cache_len = prompt_len + gen
+    ann = jax.profiler.TraceAnnotation
+    admit_span, prefill_span, decode_span, read_span = SPANS
 
-    prompts = rng.integers(0, cfg.vocab_size, size=(batch, prompt_len),
-                           dtype=np.int32)
-
-    state = lm.decode_state_init(cfg, batch, cache_len)
+    with ann(admit_span):
+        prompts = rng.integers(0, cfg.vocab_size, size=(batch, prompt_len),
+                               dtype=np.int32)
+        state = lm.decode_state_init(cfg, batch, cache_len)
     decode_fn = make_decode_fn(cfg)
 
     @jax.jit
@@ -85,10 +96,11 @@ def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
     # cache write path end to end; a fused prefill kernel is the TPU path.
     t0 = time.perf_counter()
     logits = None
-    for i in range(prompt_len):
-        tok = jnp.asarray(prompts[:, i:i + 1])
-        logits, state = decode_fn(params, state, tok, pos_at(i))
-    jax.block_until_ready(logits)
+    with ann(prefill_span):
+        for i in range(prompt_len):
+            tok = jnp.asarray(prompts[:, i:i + 1])
+            logits, state = decode_fn(params, state, tok, pos_at(i))
+        jax.block_until_ready(logits)
     t_prefill = time.perf_counter() - t0
 
     out_tokens = np.zeros((batch, gen), np.int32)
@@ -98,9 +110,11 @@ def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
         if j < keep_logits:
             kept.append(logits)
         nxt = pick(logits, j)
-        out_tokens[:, j] = np.asarray(nxt)
-        logits, state = decode_fn(params, state, nxt[:, None],
-                                  pos_at(prompt_len + j))
+        with ann(read_span):
+            out_tokens[:, j] = np.asarray(nxt)
+        with ann(decode_span):
+            logits, state = decode_fn(params, state, nxt[:, None],
+                                      pos_at(prompt_len + j))
     jax.block_until_ready(logits)
     t_decode = time.perf_counter() - t0
 

@@ -32,6 +32,29 @@ def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.compute_dtype)
 
 
+# The sublayers of the serving step, as ``jax.named_scope`` names. The
+# compiled program keeps each op's scope in its ``op_name`` metadata, and
+# ``repro.analysis.hlo.op_scopes`` maps XLA's op names back to these.
+SCOPES = ("embed", "attn/qkv", "attn/kv_write", "attn/core", "attn/out",
+          "moe/route", "moe/dispatch", "moe/experts", "moe/combine", "head")
+# nested inside a sublayer's scope, around a weight's cast to the compute
+# dtype
+CAST = "cast"
+
+
+def scope(name: str):
+    """The named scope of one sublayer of ``SCOPES``."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not one of {SCOPES}")
+    return jax.named_scope(name)
+
+
+def cast(w, dtype):
+    """A weight in ``dtype``; the conversion is traced in a ``cast`` scope."""
+    with jax.named_scope(CAST):
+        return w.astype(dtype)
+
+
 def dense_init(key, shape, scale: float | None = None, dtype=jnp.float32):
     fan_in = shape[0]
     scale = scale if scale is not None else 1.0 / math.sqrt(fan_in)
@@ -169,13 +192,16 @@ def attn_decode_readonly(params: Params, cfg: ModelConfig, x, kv_cache):
     D, H, KV, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     b, s, _ = x.shape
     cdt = _dtype(cfg)
-    q = (x @ params["wq"].astype(cdt)).reshape(b, s, H, Dh)
-    k = kv_cache["k"].transpose(0, 2, 1, 3)  # (B, Nctx, KV, Dh)
-    v = kv_cache["v"].transpose(0, 2, 1, 3)
-    k = _repeat_kv(k, H // KV)
-    v = _repeat_kv(v, H // KV)
-    out = _plain_attention(q, k, v, causal=False)
-    return out.reshape(b, s, H * Dh) @ params["wo"].astype(cdt)
+    with scope("attn/qkv"):
+        q = (x @ cast(params["wq"], cdt)).reshape(b, s, H, Dh)
+    with scope("attn/core"):
+        k = kv_cache["k"].transpose(0, 2, 1, 3)  # (B, Nctx, KV, Dh)
+        v = kv_cache["v"].transpose(0, 2, 1, 3)
+        k = _repeat_kv(k, H // KV)
+        v = _repeat_kv(v, H // KV)
+        out = _plain_attention(q, k, v, causal=False)
+    with scope("attn/out"):
+        return out.reshape(b, s, H * Dh) @ cast(params["wo"], cdt)
 
 
 def attn_apply(params: Params, cfg: ModelConfig, x, positions, *,
@@ -190,90 +216,97 @@ def attn_apply(params: Params, cfg: ModelConfig, x, positions, *,
     D, H, KV, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     b, s, _ = x.shape
     cdt = _dtype(cfg)
-    q = (x @ params["wq"].astype(cdt)).reshape(b, s, H, Dh)
-    kv_src = ctx if ctx is not None else x
-    k = (kv_src @ params["wk"].astype(cdt)).reshape(b, -1, KV, Dh)
-    v = (kv_src @ params["wv"].astype(cdt)).reshape(b, -1, KV, Dh)
-
     is_cross = ctx is not None
-    if not is_cross:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions[..., : k.shape[1]] if cache is None else positions,
-                       cfg.rope_theta)
+    with scope("attn/qkv"):
+        q = (x @ cast(params["wq"], cdt)).reshape(b, s, H, Dh)
+        kv_src = ctx if ctx is not None else x
+        k = (kv_src @ cast(params["wk"], cdt)).reshape(b, -1, KV, Dh)
+        v = (kv_src @ cast(params["wv"], cdt)).reshape(b, -1, KV, Dh)
+        if not is_cross:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions[..., : k.shape[1]] if cache is None
+                           else positions, cfg.rope_theta)
 
     new_cache = None
     if cache is not None:
         # decode: append the new token's K/V at position cache_len
         assert s == 1
-        k_cache, v_cache = cache["k"], cache["v"]     # (B, KV, Smax, Dh)
-        pos = cache_len                                # (B,) int32
-        if cfg.kv_update == "dus":
-            # per-example dynamic_update_slice — a true scatter; avoids the
-            # one_hot broadcast that forces SPMD full rematerialization of
-            # the seq-sharded cache (see EXPERIMENTS.md §Perf cell B)
-            def _upd(c, n, p):
-                return lax.dynamic_update_slice(c, n, (0, p, 0))
-            k_cache = jax.vmap(_upd)(k_cache, k.transpose(0, 2, 1, 3), pos)
-            v_cache = jax.vmap(_upd)(v_cache, v.transpose(0, 2, 1, 3), pos)
-        else:
-            oh = jax.nn.one_hot(pos, k_cache.shape[2], dtype=k.dtype)
-            k_cache = k_cache + oh[:, None, :, None] * k.transpose(0, 2, 1, 3)
-            v_cache = v_cache + oh[:, None, :, None] * v.transpose(0, 2, 1, 3)
-        new_cache = {"k": k_cache, "v": v_cache}
+        with scope("attn/kv_write"):
+            k_cache, v_cache = cache["k"], cache["v"]     # (B, KV, Smax, Dh)
+            pos = cache_len                                # (B,) int32
+            if cfg.kv_update == "dus":
+                # per-example dynamic_update_slice — a true scatter; avoids
+                # the one_hot broadcast that forces SPMD full
+                # rematerialization of the seq-sharded cache (see
+                # EXPERIMENTS.md §Perf cell B)
+                def _upd(c, n, p):
+                    return lax.dynamic_update_slice(c, n, (0, p, 0))
+                k_cache = jax.vmap(_upd)(k_cache, k.transpose(0, 2, 1, 3), pos)
+                v_cache = jax.vmap(_upd)(v_cache, v.transpose(0, 2, 1, 3), pos)
+            else:
+                oh = jax.nn.one_hot(pos, k_cache.shape[2], dtype=k.dtype)
+                k_cache = (k_cache
+                           + oh[:, None, :, None] * k.transpose(0, 2, 1, 3))
+                v_cache = (v_cache
+                           + oh[:, None, :, None] * v.transpose(0, 2, 1, 3))
+            new_cache = {"k": k_cache, "v": v_cache}
         smax = k_cache.shape[2]
-        if (cfg.decode_attn == "flashdecode" and dist is not None
-                and dist.model_size > 1 and smax % dist.model_size == 0):
-            # flash-decoding: the cache stays SEQ-sharded end to end.
-            # q is tiny (B,1,H,Dh) — replicate it; scores are S-sharded;
-            # softmax over the sharded axis lowers to partial-max/sum
-            # psums of (B,H,1) scalars instead of gathering the cache
-            # (the measured 1 GiB/layer/step pathology; §Perf cell B).
-            q_r = lax.with_sharding_constraint(
-                q, jax.sharding.NamedSharding(
-                    dist.mesh, jax.sharding.PartitionSpec(
-                        dist.bspec, None, None, None)))
-            kc = dist.constrain_kv(k_cache)            # (B, KV, S, Dh)
-            vc = dist.constrain_kv(v_cache)
-            scale = 1.0 / math.sqrt(Dh)
-            scores = jnp.einsum(
-                "bqhd,bhsd->bhqs", q_r,
-                jnp.repeat(kc, H // KV, axis=1),
-                preferred_element_type=jnp.float32) * scale
-            scores = dist.constrain_scores(scores)     # (B, H, 1, S)@model
-            valid = (jnp.arange(smax)[None, None, None, :]
-                     < (cache_len + 1)[:, None, None, None])
-            scores = jnp.where(valid, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bhqs,bhsd->bqhd",
-                             probs.astype(q.dtype),
-                             jnp.repeat(vc, H // KV, axis=1),
-                             preferred_element_type=jnp.float32
-                             ).astype(q.dtype)
-        else:
-            k_full = k_cache.transpose(0, 2, 1, 3)     # (B, Smax, KV, Dh)
-            v_full = v_cache.transpose(0, 2, 1, 3)
-            k_full = _repeat_kv(k_full, H // KV)
-            v_full = _repeat_kv(v_full, H // KV)
-            out = _plain_attention(q, k_full, v_full, causal=False,
-                                   kv_len=cache_len + 1)
+        with scope("attn/core"):
+            if (cfg.decode_attn == "flashdecode" and dist is not None
+                    and dist.model_size > 1 and smax % dist.model_size == 0):
+                # flash-decoding: the cache stays SEQ-sharded end to end.
+                # q is tiny (B,1,H,Dh) — replicate it; scores are S-sharded;
+                # softmax over the sharded axis lowers to partial-max/sum
+                # psums of (B,H,1) scalars instead of gathering the cache
+                # (the measured 1 GiB/layer/step pathology; §Perf cell B).
+                q_r = lax.with_sharding_constraint(
+                    q, jax.sharding.NamedSharding(
+                        dist.mesh, jax.sharding.PartitionSpec(
+                            dist.bspec, None, None, None)))
+                kc = dist.constrain_kv(k_cache)            # (B, KV, S, Dh)
+                vc = dist.constrain_kv(v_cache)
+                scale = 1.0 / math.sqrt(Dh)
+                scores = jnp.einsum(
+                    "bqhd,bhsd->bhqs", q_r,
+                    jnp.repeat(kc, H // KV, axis=1),
+                    preferred_element_type=jnp.float32) * scale
+                scores = dist.constrain_scores(scores)     # (B, H, 1, S)@model
+                valid = (jnp.arange(smax)[None, None, None, :]
+                         < (cache_len + 1)[:, None, None, None])
+                scores = jnp.where(valid, scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+                out = jnp.einsum("bhqs,bhsd->bqhd",
+                                 probs.astype(q.dtype),
+                                 jnp.repeat(vc, H // KV, axis=1),
+                                 preferred_element_type=jnp.float32
+                                 ).astype(q.dtype)
+            else:
+                k_full = k_cache.transpose(0, 2, 1, 3)     # (B, Smax, KV, Dh)
+                v_full = v_cache.transpose(0, 2, 1, 3)
+                k_full = _repeat_kv(k_full, H // KV)
+                v_full = _repeat_kv(v_full, H // KV)
+                out = _plain_attention(q, k_full, v_full, causal=False,
+                                       kv_len=cache_len + 1)
     else:
-        k = _repeat_kv(k, H // KV)
-        v = _repeat_kv(v, H // KV)
-        if (dist is not None and cfg.attn_seq_shard and not is_cross
-                and s % max(dist.model_size, 1) == 0):
-            # context parallelism: scores (B, H, S/TP, S) per device —
-            # the remedy when heads cannot split the model axis
-            q = dist.constrain_seq(q)
-        chunk = cfg.attn_chunk or (1024 if s > 8192 else 0)
-        if chunk and not is_cross and s % chunk == 0:
-            out = _chunked_attention(q, k, v, causal=True,
-                                     q_chunk=chunk, kv_chunk=chunk)
-        else:
-            out = _plain_attention(q, k, v, causal=not is_cross)
-        if dist is not None and cfg.attn_seq_shard and not is_cross:
-            out = dist.constrain_seq(out)
-    out = out.reshape(b, s, H * Dh)
-    return out @ params["wo"].astype(cdt), new_cache
+        with scope("attn/core"):
+            k = _repeat_kv(k, H // KV)
+            v = _repeat_kv(v, H // KV)
+            if (dist is not None and cfg.attn_seq_shard and not is_cross
+                    and s % max(dist.model_size, 1) == 0):
+                # context parallelism: scores (B, H, S/TP, S) per device —
+                # the remedy when heads cannot split the model axis
+                q = dist.constrain_seq(q)
+            chunk = cfg.attn_chunk or (1024 if s > 8192 else 0)
+            if chunk and not is_cross and s % chunk == 0:
+                out = _chunked_attention(q, k, v, causal=True,
+                                         q_chunk=chunk, kv_chunk=chunk)
+            else:
+                out = _plain_attention(q, k, v, causal=not is_cross)
+            if dist is not None and cfg.attn_seq_shard and not is_cross:
+                out = dist.constrain_seq(out)
+    with scope("attn/out"):
+        out = out.reshape(b, s, H * Dh)
+        return out @ cast(params["wo"], cdt), new_cache
 
 
 # --------------------------------------------------------------------------
@@ -293,9 +326,9 @@ def ffn_init(key, cfg: ModelConfig) -> Params:
 
 def ffn_apply(params: Params, cfg: ModelConfig, x):
     cdt = _dtype(cfg)
-    g = x @ params["w_gate"].astype(cdt)
-    u = x @ params["w_up"].astype(cdt)
-    return (jax.nn.silu(g) * u) @ params["w_down"].astype(cdt)
+    g = x @ cast(params["w_gate"], cdt)
+    u = x @ cast(params["w_up"], cdt)
+    return (jax.nn.silu(g) * u) @ cast(params["w_down"], cdt)
 
 
 def cmix_init(key, cfg: ModelConfig) -> Params:
@@ -372,60 +405,73 @@ def moe_apply(params: Params, cfg: ModelConfig, x):
         xg = x.reshape(groups, gtok, d)
     C = moe_capacity(cfg, gtok)
 
-    logits = (xg @ params["router"].astype(cdt)).astype(jnp.float32)  # (G,T,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = lax.top_k(probs, K)                                # (G,T,K)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    with scope("moe/route"):
+        logits = (xg @ cast(params["router"], cdt)).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                  # (G,T,E)
+        top_p, top_e = lax.top_k(probs, K)                       # (G,T,K)
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
-    # position of each (token, k) inside its expert queue
-    onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)              # (G,T,K,E)
-    flat = onehot.reshape(groups, gtok * K, E)
-    pos = jnp.cumsum(flat, axis=1) - flat                             # (G,T*K,E)
-    pos = jnp.einsum("gte,gte->gt", pos, flat).reshape(groups, gtok, K)
-    keep = pos < C
-    pos = pos.astype(jnp.int32)
+        # position of each (token, k) inside its expert queue
+        onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)     # (G,T,K,E)
+        flat = onehot.reshape(groups, gtok * K, E)
+        pos = jnp.cumsum(flat, axis=1) - flat                    # (G,T*K,E)
+        pos = jnp.einsum("gte,gte->gt", pos, flat).reshape(groups, gtok, K)
+        keep = pos < C
+        pos = pos.astype(jnp.int32)
 
-    # scatter token indices into (G, E, C) dispatch table
-    tok_ids = jnp.broadcast_to(jnp.arange(gtok)[None, :, None], top_e.shape)
-    dispatch = jnp.full((groups, E, C), gtok, jnp.int32)  # gtok == OOB sentinel
-    gidx = jnp.broadcast_to(jnp.arange(groups)[:, None, None], top_e.shape)
-    dispatch = dispatch.at[
-        gidx.reshape(groups, -1),
-        jnp.where(keep, top_e, 0).reshape(groups, -1),
-        jnp.where(keep, pos, C - 1).reshape(groups, -1),
-    ].set(jnp.where(keep, tok_ids, gtok).reshape(groups, -1), mode="drop")
+    with scope("moe/dispatch"):
+        # scatter token indices into (G, E, C) dispatch table
+        tok_ids = jnp.broadcast_to(jnp.arange(gtok)[None, :, None],
+                                   top_e.shape)
+        # gtok == OOB sentinel
+        dispatch = jnp.full((groups, E, C), gtok, jnp.int32)
+        gidx = jnp.broadcast_to(jnp.arange(groups)[:, None, None],
+                                top_e.shape)
+        dispatch = dispatch.at[
+            gidx.reshape(groups, -1),
+            jnp.where(keep, top_e, 0).reshape(groups, -1),
+            jnp.where(keep, pos, C - 1).reshape(groups, -1),
+        ].set(jnp.where(keep, tok_ids, gtok).reshape(groups, -1), mode="drop")
 
-    # gather expert inputs (OOB sentinel -> zeros via fill)
-    xpad = jnp.concatenate([xg, jnp.zeros((groups, 1, d), xg.dtype)], axis=1)
-    expert_in = jnp.take_along_axis(
-        xpad[:, None], dispatch[..., None].clip(0, gtok), axis=2
-    )  # (G, E, C, D)
+        # gather expert inputs (OOB sentinel -> zeros via fill)
+        xpad = jnp.concatenate([xg, jnp.zeros((groups, 1, d), xg.dtype)],
+                               axis=1)
+        expert_in = jnp.take_along_axis(
+            xpad[:, None], dispatch[..., None].clip(0, gtok), axis=2
+        )  # (G, E, C, D)
 
-    h_g = jnp.einsum("gecd,edf->gecf", expert_in, params["w_gate"].astype(cdt))
-    h_u = jnp.einsum("gecd,edf->gecf", expert_in, params["w_up"].astype(cdt))
-    h = jax.nn.silu(h_g) * h_u
-    expert_out = jnp.einsum("gecf,efd->gecd", h, params["w_down"].astype(cdt))
+    with scope("moe/experts"):
+        h_g = jnp.einsum("gecd,edf->gecf", expert_in,
+                         cast(params["w_gate"], cdt))
+        h_u = jnp.einsum("gecd,edf->gecf", expert_in,
+                         cast(params["w_up"], cdt))
+        h = jax.nn.silu(h_g) * h_u
+        expert_out = jnp.einsum("gecf,efd->gecd", h,
+                                cast(params["w_down"], cdt))
 
-    # combine: weight each dispatched slot and scatter-add back to tokens.
-    # slot weights mirror the dispatch scatter; the OOB sentinel token id
-    # (== gtok) lands in the padding row and is dropped by the final slice.
-    slot_w = jnp.zeros((groups, E, C), jnp.float32)
-    slot_w = slot_w.at[
-        gidx.reshape(groups, -1),
-        jnp.where(keep, top_e, 0).reshape(groups, -1),
-        jnp.where(keep, pos, C - 1).reshape(groups, -1),
-    ].add(jnp.where(keep, top_p, 0.0).reshape(groups, -1), mode="drop")
-    weighted = (expert_out.astype(jnp.float32)
-                * slot_w[..., None]).reshape(groups, E * C, d)
-    g_rows = jnp.broadcast_to(jnp.arange(groups)[:, None], (groups, E * C))
-    out = jnp.zeros((groups, gtok + 1, d), jnp.float32)
-    out = out.at[g_rows, dispatch.reshape(groups, -1)].add(weighted, mode="drop")
-    y = out[:, :gtok].astype(cdt)
+    with scope("moe/combine"):
+        # weight each dispatched slot and scatter-add back to tokens. slot
+        # weights mirror the dispatch scatter; the OOB sentinel token id
+        # (== gtok) lands in the padding row and is dropped by the final slice.
+        slot_w = jnp.zeros((groups, E, C), jnp.float32)
+        slot_w = slot_w.at[
+            gidx.reshape(groups, -1),
+            jnp.where(keep, top_e, 0).reshape(groups, -1),
+            jnp.where(keep, pos, C - 1).reshape(groups, -1),
+        ].add(jnp.where(keep, top_p, 0.0).reshape(groups, -1), mode="drop")
+        weighted = (expert_out.astype(jnp.float32)
+                    * slot_w[..., None]).reshape(groups, E * C, d)
+        g_rows = jnp.broadcast_to(jnp.arange(groups)[:, None], (groups, E * C))
+        out = jnp.zeros((groups, gtok + 1, d), jnp.float32)
+        out = out.at[g_rows, dispatch.reshape(groups, -1)].add(
+            weighted, mode="drop")
+        y = out[:, :gtok].astype(cdt)
 
-    # load-balancing auxiliary loss (Switch-style)
-    me = probs.mean(axis=(0, 1))                       # (E,)
-    ce = onehot.sum(axis=2).mean(axis=(0, 1))          # fraction routed per e
-    aux = E * jnp.sum(me * ce / K)
+    with scope("moe/route"):
+        # load-balancing auxiliary loss (Switch-style)
+        me = probs.mean(axis=(0, 1))                       # (E,)
+        ce = onehot.sum(axis=2).mean(axis=(0, 1))     # fraction routed per e
+        aux = E * jnp.sum(me * ce / K)
     if s == 1:
         y = y.reshape(b, s, d)
     return y, aux
@@ -459,59 +505,65 @@ def moe_apply_ep(params: Params, cfg: ModelConfig, x, dist):
         b, s, d = xx.shape
         gtok = b * s
         xg = xx.reshape(1, gtok, d)
-        logits = (xg @ router.astype(cdt)).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)                # (1,T,E)
-        top_p, top_e = lax.top_k(probs, K)
-        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+        with scope("moe/route"):
+            logits = (xg @ cast(router, cdt)).astype(jnp.float32)
+            probs = jax.nn.softmax(logits, axis=-1)                # (1,T,E)
+            top_p, top_e = lax.top_k(probs, K)
+            top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
-        # positions within each GLOBAL expert queue (identical math on
-        # every shard — routing is deterministic), then keep only the
-        # local expert range
-        onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)
-        flat = onehot.reshape(1, gtok * K, E)
-        pos = jnp.cumsum(flat, axis=1) - flat
-        pos = jnp.einsum("gte,gte->gt", pos, flat).reshape(1, gtok, K)
-        C = moe_capacity(cfg, gtok)
-        local = (top_e >= idx * E_loc) & (top_e < (idx + 1) * E_loc)
-        keep = (pos < C) & local
-        e_loc = jnp.where(local, top_e - idx * E_loc, 0)
-        pos = pos.astype(jnp.int32)
+            # positions within each GLOBAL expert queue (identical math on
+            # every shard — routing is deterministic), then keep only the
+            # local expert range
+            onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)
+            flat = onehot.reshape(1, gtok * K, E)
+            pos = jnp.cumsum(flat, axis=1) - flat
+            pos = jnp.einsum("gte,gte->gt", pos, flat).reshape(1, gtok, K)
+            C = moe_capacity(cfg, gtok)
+            local = (top_e >= idx * E_loc) & (top_e < (idx + 1) * E_loc)
+            keep = (pos < C) & local
+            e_loc = jnp.where(local, top_e - idx * E_loc, 0)
+            pos = pos.astype(jnp.int32)
 
-        tok_ids = jnp.broadcast_to(jnp.arange(gtok)[None, :, None],
-                                   top_e.shape)
-        dispatch = jnp.full((1, E_loc, C), gtok, jnp.int32)
-        gidx = jnp.zeros_like(top_e)
-        dispatch = dispatch.at[
-            gidx.reshape(1, -1),
-            jnp.where(keep, e_loc, 0).reshape(1, -1),
-            jnp.where(keep, pos, C - 1).reshape(1, -1),
-        ].set(jnp.where(keep, tok_ids, gtok).reshape(1, -1), mode="drop")
+        with scope("moe/dispatch"):
+            tok_ids = jnp.broadcast_to(jnp.arange(gtok)[None, :, None],
+                                       top_e.shape)
+            dispatch = jnp.full((1, E_loc, C), gtok, jnp.int32)
+            gidx = jnp.zeros_like(top_e)
+            dispatch = dispatch.at[
+                gidx.reshape(1, -1),
+                jnp.where(keep, e_loc, 0).reshape(1, -1),
+                jnp.where(keep, pos, C - 1).reshape(1, -1),
+            ].set(jnp.where(keep, tok_ids, gtok).reshape(1, -1), mode="drop")
 
-        xpad = jnp.concatenate([xg, jnp.zeros((1, 1, d), xg.dtype)], axis=1)
-        expert_in = jnp.take_along_axis(
-            xpad[:, None], dispatch[..., None].clip(0, gtok), axis=2)
-        h_g = jnp.einsum("gecd,edf->gecf", expert_in, wg.astype(cdt))
-        h_u = jnp.einsum("gecd,edf->gecf", expert_in, wu.astype(cdt))
-        h = jax.nn.silu(h_g) * h_u
-        expert_out = jnp.einsum("gecf,efd->gecd", h, wd.astype(cdt))
+            xpad = jnp.concatenate([xg, jnp.zeros((1, 1, d), xg.dtype)],
+                                   axis=1)
+            expert_in = jnp.take_along_axis(
+                xpad[:, None], dispatch[..., None].clip(0, gtok), axis=2)
+        with scope("moe/experts"):
+            h_g = jnp.einsum("gecd,edf->gecf", expert_in, cast(wg, cdt))
+            h_u = jnp.einsum("gecd,edf->gecf", expert_in, cast(wu, cdt))
+            h = jax.nn.silu(h_g) * h_u
+            expert_out = jnp.einsum("gecf,efd->gecd", h, cast(wd, cdt))
 
-        slot_w = jnp.zeros((1, E_loc, C), jnp.float32)
-        slot_w = slot_w.at[
-            gidx.reshape(1, -1),
-            jnp.where(keep, e_loc, 0).reshape(1, -1),
-            jnp.where(keep, pos, C - 1).reshape(1, -1),
-        ].add(jnp.where(keep, top_p, 0.0).reshape(1, -1), mode="drop")
-        weighted = (expert_out.astype(jnp.float32)
-                    * slot_w[..., None]).reshape(1, E_loc * C, d)
-        g_rows = jnp.zeros((1, E_loc * C), jnp.int32)
-        out = jnp.zeros((1, gtok + 1, d), jnp.float32)
-        out = out.at[g_rows, dispatch.reshape(1, -1)].add(weighted,
-                                                          mode="drop")
-        y = lax.psum(out[:, :gtok], "model")   # combine partial outputs
-        # aux loss: every shard sees all routing info — no comm needed
-        me = probs.mean(axis=(0, 1))
-        ce = onehot.sum(axis=2).mean(axis=(0, 1))
-        aux = E * jnp.sum(me * ce / K)
+        with scope("moe/combine"):
+            slot_w = jnp.zeros((1, E_loc, C), jnp.float32)
+            slot_w = slot_w.at[
+                gidx.reshape(1, -1),
+                jnp.where(keep, e_loc, 0).reshape(1, -1),
+                jnp.where(keep, pos, C - 1).reshape(1, -1),
+            ].add(jnp.where(keep, top_p, 0.0).reshape(1, -1), mode="drop")
+            weighted = (expert_out.astype(jnp.float32)
+                        * slot_w[..., None]).reshape(1, E_loc * C, d)
+            g_rows = jnp.zeros((1, E_loc * C), jnp.int32)
+            out = jnp.zeros((1, gtok + 1, d), jnp.float32)
+            out = out.at[g_rows, dispatch.reshape(1, -1)].add(weighted,
+                                                              mode="drop")
+            y = lax.psum(out[:, :gtok], "model")   # combine partial outputs
+        with scope("moe/route"):
+            # aux loss: every shard sees all routing info — no comm needed
+            me = probs.mean(axis=(0, 1))
+            ce = onehot.sum(axis=2).mean(axis=(0, 1))
+            aux = E * jnp.sum(me * ce / K)
         return y.reshape(b, s, d).astype(cdt), aux
 
     bspec = dist.bspec

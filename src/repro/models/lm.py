@@ -14,6 +14,7 @@ ever materialized (see distributed/vocab_parallel.py).
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any, Optional
 
@@ -141,7 +142,8 @@ def _apply_block(bp: Params, cfg: ModelConfig, blk: BlockSpec, x, positions,
                  dist=None):
     """Returns (x, new_state, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
-    h = B.rmsnorm(x, bp["norm1"], cfg.norm_eps)
+    with _norm_scope(blk.mixer in ("attn", "cross_attn"), "attn/qkv"):
+        h = B.rmsnorm(x, bp["norm1"], cfg.norm_eps)
     new_state: Params = {}
 
     if blk.mixer in ("attn", "cross_attn"):
@@ -180,7 +182,8 @@ def _apply_block(bp: Params, cfg: ModelConfig, blk: BlockSpec, x, positions,
     else:
         x = x + mix
         if blk.ffn != "none":
-            h2 = B.rmsnorm(x, bp["norm2"], cfg.norm_eps)
+            with _norm_scope(blk.ffn == "moe", "moe/route"):
+                h2 = B.rmsnorm(x, bp["norm2"], cfg.norm_eps)
             f, aux2, fstate = _apply_ffn(bp, cfg, blk, h2, state, train,
                                          dist=dist)
             x = x + f
@@ -189,6 +192,11 @@ def _apply_block(bp: Params, cfg: ModelConfig, blk: BlockSpec, x, positions,
     aux = aux + aux2
     new_state.update(fstate)
     return x, new_state, aux
+
+
+def _norm_scope(scoped: bool, name: str):
+    """A pre-norm belongs to the sublayer it feeds, where that has a scope."""
+    return B.scope(name) if scoped else contextlib.nullcontext()
 
 
 def _apply_ffn(bp, cfg, blk, h, state, train, dist=None):
@@ -220,9 +228,10 @@ def _embed_tokens(params, cfg: ModelConfig, batch, dist=None):
     if cfg.frontend == "frames" and "frames" in batch:
         return batch["frames"].astype(cdt)
     tokens = batch["tokens"]
-    if dist is not None and dist.vocab_parallel(cfg):
-        return dist.vp_embed(params["embed"], tokens, cfg)
-    return params["embed"].astype(cdt)[tokens]
+    with B.scope("embed"):
+        if dist is not None and dist.vocab_parallel(cfg):
+            return dist.vp_embed(params["embed"], tokens, cfg)
+        return B.cast(params["embed"], cdt)[tokens]
 
 
 def forward(params: Params, cfg: ModelConfig, batch, *, dist=None,
@@ -320,15 +329,16 @@ def decode_step(params: Params, cfg: ModelConfig, state, batch, pos, *,
         return xc, tuple(new_gs)
 
     x, new_state = lax.scan(group_step, x, (params["blocks"], state))
-    x = B.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = lm_head(params, cfg)
-    if (cfg.decode_return == "token" and dist is not None
-            and dist.vocab_parallel(cfg)):
-        # greedy token id per row; the (B, V) logits never materialize
-        token = dist.vp_greedy_token(head, x[:, 0], cfg)
-        return token, new_state
-    logits = (x[:, 0] @ head.astype(x.dtype).T).astype(jnp.float32)
-    return logits[..., : cfg.vocab_size], new_state
+    with B.scope("head"):
+        x = B.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = lm_head(params, cfg)
+        if (cfg.decode_return == "token" and dist is not None
+                and dist.vocab_parallel(cfg)):
+            # greedy token id per row; the (B, V) logits never materialize
+            token = dist.vp_greedy_token(head, x[:, 0], cfg)
+            return token, new_state
+        logits = (x[:, 0] @ B.cast(head, x.dtype).T).astype(jnp.float32)
+        return logits[..., : cfg.vocab_size], new_state
 
 
 def prefill(params: Params, cfg: ModelConfig, batch, *, dist=None):
@@ -338,6 +348,7 @@ def prefill(params: Params, cfg: ModelConfig, batch, *, dist=None):
     exercised by decode_step from decode_state_init; the prefill benchmark
     shape measures the forward itself, which dominates.)"""
     x, _ = forward(params, cfg, batch, dist=dist)
-    head = lm_head(params, cfg)
-    logits = (x[:, -1] @ head.astype(x.dtype).T).astype(jnp.float32)
-    return logits[..., : cfg.vocab_size]
+    with B.scope("head"):
+        head = lm_head(params, cfg)
+        logits = (x[:, -1] @ B.cast(head, x.dtype).T).astype(jnp.float32)
+        return logits[..., : cfg.vocab_size]

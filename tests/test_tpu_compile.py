@@ -76,3 +76,48 @@ def test_wkv6_rwkv6_3b_heads_compiles(one_chip):
     x = ((b, h, s, n), jnp.float32)
     _compile(lambda r, k, v, w, u: wkv6(r, k, v, w, u, interpret=False),
              one_chip, x, x, x, x, ((h, n), jnp.float32))
+
+
+def test_granite_decode_step_scopes(one_chip):
+    """granite-moe-1b-a400m's serving step at 128 slots of 256, as the
+    chip's compiler leaves it: XLA hoists each weight's f32 -> bf16 cast
+    out of the layer loop without its metadata, and ``op_scopes`` still
+    reads the expert weights' casts as ``cast``; every matmul and every
+    sublayer has its scope."""
+    import dataclasses
+    import re
+
+    from repro.analysis import hlo as H
+    from repro.configs import get_config
+    from repro.launch.serve import make_decode_fn
+    from repro.models import blocks, lm
+
+    cfg = get_config("granite-moe-1b-a400m")
+    cfg = dataclasses.replace(
+        cfg, norm_eps=1e-6, tie_embeddings=True,
+        moe=dataclasses.replace(cfg.moe, capacity_factor=4.0))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0))))
+    state = on_chip(lm.decode_state_specs(cfg, 128, 256))
+    tok = jax.ShapeDtypeStruct((128, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one_chip)
+    text = make_decode_fn(cfg).lower(params, state, tok, pos).compile(
+        ).as_text()
+    scopes = H.op_scopes(text)
+    comps = H._split_computations(text)
+    entry = next(c for c in comps.values() if c.is_entry)
+    experts = [i.name for i in entry.instrs if i.op == "convert"
+               and re.search(r"ffn____w_(gate|up|down)__", i.line)]
+    assert len(experts) == 3
+    assert {scopes[n] for n in experts} == {blocks.CAST}
+    matmuls = [i.name for c in comps.values() for i in c.instrs
+               if i.op == "fusion" and "convolution(" in "\n".join(
+                   j.line for j in comps[re.search(
+                       r"calls=%?([\w.\-]+)", i.line).group(1)].instrs)]
+    assert matmuls
+    assert {scopes[n] for n in matmuls} <= set(blocks.SCOPES)
+    assert set(blocks.SCOPES) <= set(scopes.values())
