@@ -133,6 +133,27 @@ def _plain_attention(q, k, v, causal: bool, q_offset=0, kv_len: Optional[jax.Arr
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _decode_attention(q, k_cache, v_cache, kv_len):
+    """One query row over the cache as it is stored, in grouped-query form.
+
+    q: (B, 1, H, Dh); k_cache, v_cache: (B, KV, Smax, Dh); kv_len: (B,)
+    valid positions. Query head h reads KV head h // (H // KV), as
+    ``_repeat_kv`` maps them, but the cache is read in place: no
+    transpose, no head repetition, no cast. fp32 scores and softmax.
+    """
+    b, _, h, dh = q.shape
+    kv, smax = k_cache.shape[1], k_cache.shape[2]
+    q = q.reshape(b, kv, h // kv, dh)
+    scale = 1.0 / math.sqrt(dh)
+    scores = jnp.einsum("bkgd,bksd->bkgs", q, k_cache,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(smax)[None, None, None, :] < kv_len[:, None, None, None]
+    scores = jnp.where(valid, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgs,bksd->bkgd", probs, v_cache)
+    return out.reshape(b, 1, h, dh)
+
+
 def _chunked_attention(q, k, v, causal: bool, q_chunk: int, kv_chunk: int):
     """Memory-efficient (online-softmax) attention; never materializes SxS.
 
@@ -281,12 +302,7 @@ def attn_apply(params: Params, cfg: ModelConfig, x, positions, *,
                                  preferred_element_type=jnp.float32
                                  ).astype(q.dtype)
             else:
-                k_full = k_cache.transpose(0, 2, 1, 3)     # (B, Smax, KV, Dh)
-                v_full = v_cache.transpose(0, 2, 1, 3)
-                k_full = _repeat_kv(k_full, H // KV)
-                v_full = _repeat_kv(v_full, H // KV)
-                out = _plain_attention(q, k_full, v_full, causal=False,
-                                       kv_len=cache_len + 1)
+                out = _decode_attention(q, k_cache, v_cache, cache_len + 1)
     else:
         with scope("attn/core"):
             k = _repeat_kv(k, H // KV)

@@ -8,6 +8,7 @@ interpret-mode tests in test_kernels.py cannot see. The topology is built
 in a module fixture, never at import, so every xdist worker collects the
 same tests and only the worker that runs this file loads libtpu.
 """
+import math
 import os
 
 import jax
@@ -78,19 +79,19 @@ def test_wkv6_rwkv6_3b_heads_compiles(one_chip):
              one_chip, x, x, x, x, ((h, n), jnp.float32))
 
 
-def test_granite_decode_step_scopes(one_chip):
-    """granite-moe-1b-a400m's serving step at 128 slots of 256, as the
-    chip's compiler leaves it: XLA hoists each weight's f32 -> bf16 cast
-    out of the layer loop without its metadata, and ``op_scopes`` still
-    reads the expert weights' casts as ``cast``; every matmul and every
-    sublayer has its scope."""
-    import dataclasses
-    import re
+# granite-moe-1b-a400m served at 128 slots of 256 positions
+GRANITE_SLOTS, GRANITE_SMAX = 128, 256
 
-    from repro.analysis import hlo as H
+
+@pytest.fixture(scope="module")
+def granite_step(one_chip):
+    """granite-moe-1b-a400m's serving step at 128 slots of 256, compiled
+    for the described chip: (config, optimized HLO text)."""
+    import dataclasses
+
     from repro.configs import get_config
     from repro.launch.serve import make_decode_fn
-    from repro.models import blocks, lm
+    from repro.models import lm
 
     cfg = get_config("granite-moe-1b-a400m")
     cfg = dataclasses.replace(
@@ -102,11 +103,28 @@ def test_granite_decode_step_scopes(one_chip):
             s.shape, s.dtype, sharding=one_chip), tree)
 
     params = on_chip(jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0))))
-    state = on_chip(lm.decode_state_specs(cfg, 128, 256))
-    tok = jax.ShapeDtypeStruct((128, 1), jnp.int32, sharding=one_chip)
-    pos = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one_chip)
+    state = on_chip(lm.decode_state_specs(cfg, GRANITE_SLOTS, GRANITE_SMAX))
+    tok = jax.ShapeDtypeStruct((GRANITE_SLOTS, 1), jnp.int32,
+                               sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((GRANITE_SLOTS,), jnp.int32,
+                               sharding=one_chip)
     text = make_decode_fn(cfg).lower(params, state, tok, pos).compile(
         ).as_text()
+    return cfg, text
+
+
+def test_granite_decode_step_scopes(granite_step):
+    """granite-moe-1b-a400m's serving step at 128 slots of 256, as the
+    chip's compiler leaves it: XLA hoists each weight's f32 -> bf16 cast
+    out of the layer loop without its metadata, and ``op_scopes`` still
+    reads the expert weights' casts as ``cast``; every matmul and every
+    sublayer has its scope."""
+    import re
+
+    from repro.analysis import hlo as H
+    from repro.models import blocks
+
+    _, text = granite_step
     scopes = H.op_scopes(text)
     comps = H._split_computations(text)
     entry = next(c for c in comps.values() if c.is_entry)
@@ -121,3 +139,38 @@ def test_granite_decode_step_scopes(one_chip):
     assert matmuls
     assert {scopes[n] for n in matmuls} <= set(blocks.SCOPES)
     assert set(blocks.SCOPES) <= set(scopes.values())
+
+
+def test_granite_decode_attention_reads_cache_in_place(granite_step):
+    """Decode attention reads the bf16 cache as stored: no array that an
+    op of ``attn/core`` or ``attn/kv_write`` writes to memory is an f32
+    copy of a layer's cache, or the cache repeated to all 16 query heads
+    (in any dtype or layout). Ops inside a fusion write nothing to
+    memory, so only the ops of unfused computations are read."""
+    import re
+
+    from repro.analysis import hlo as H
+
+    cfg, text = granite_step
+    per_layer = GRANITE_SLOTS * GRANITE_SMAX * cfg.d_head
+    cache = per_layer * cfg.n_kv_heads
+    repeated = per_layer * cfg.n_heads
+    assert cfg.n_heads > cfg.n_kv_heads        # GQA: a repeat is visible
+    scopes = H.op_scopes(text)
+    comps = H._split_computations(text)
+    fused = {re.search(r"calls=%?([\w.\-]+)", i.line).group(1)
+             for c in comps.values() for i in c.instrs if i.op == "fusion"}
+    seen = 0
+    for c in comps.values():
+        if c.name in fused:
+            continue
+        for i in c.instrs:
+            if scopes.get(i.name) not in ("attn/core", "attn/kv_write"):
+                continue
+            seen += 1
+            for dt, dims in H._SHAPE_RE.findall(i.shape):
+                n = math.prod(int(d) for d in dims.split(",") if d)
+                assert not (dt == "f32" and n >= cache), (i.name, i.shape)
+                assert n not in (repeated, cfg.n_layers * repeated), (
+                    i.name, i.shape)
+    assert seen
