@@ -37,8 +37,9 @@ SPANS = ("batch_admit", "prefill", "decode", "host_read")
 
 
 def make_decode_fn(cfg):
-    """The jitted one-token serving step: (params, state, tok (B, 1),
-    pos (B,)) -> (logits (B, vocab), state)."""
+    """The one-token serving step: (params, state, tok (B, 1), pos (B,))
+    -> (logits (B, vocab), state), on weights held in the compute dtype
+    (``DecodeStep``)."""
     @jax.jit
     def decode_fn(params, state, tok, pos):
         b = {"tokens": tok}
@@ -49,7 +50,57 @@ def make_decode_fn(cfg):
                 b = {"frames": emb[tok[:, 0]][:, None, :]}
         return lm.decode_step(params, cfg, state, b, pos)
 
-    return decode_fn
+    return DecodeStep(cfg, decode_fn)
+
+
+class DecodeStep:
+    """A jitted serving step (``core``) that is handed the caller's
+    weights as ``lm.serving_params`` makes them, so the step reads them in
+    the compute dtype and casts none. The copy is made on the first call
+    and held while the caller passes the same leaves (by identity; their
+    references are kept, so an id is never reused); other leaves replace
+    it. Nothing is held where no weight needs a cast, nor where the leaves
+    are tracers (the step called under another transformation): the core
+    then runs on what it was given.
+
+    ``weights_prepared`` counts the copies made, ``weights_reused`` the
+    calls served from the held copy."""
+
+    def __init__(self, cfg, core):
+        self.cfg, self.core = cfg, core
+        self.weights_prepared = 0
+        self.weights_reused = 0
+        self._held = None            # (the caller's leaves, their copy)
+
+    def __call__(self, params, state, tok, pos):
+        return self.core(self.weights(params), state, tok, pos)
+
+    def weights(self, params):
+        """The weights the core is handed for ``params``."""
+        leaves = jax.tree.leaves(params)
+        if any(isinstance(w, jax.core.Tracer) for w in leaves):
+            return params
+        if self._held is not None and len(self._held[0]) == len(leaves) \
+                and all(a is b for a, b in zip(self._held[0], leaves)):
+            self.weights_reused += 1
+            return self._held[1]
+        self._held = None            # at most one copy is ever held
+        prepared = lm.serving_params(self.cfg, params)
+        if prepared is not params:
+            self._held = (leaves, prepared)
+            self.weights_prepared += 1
+        return prepared
+
+    def lower(self, params, state, tok, pos):
+        """The core lowered for what a call with these arguments runs:
+        the parameter specs mapped through the preparation first."""
+        specs = jax.eval_shape(lambda p: lm.serving_params(self.cfg, p),
+                               params)
+        specs = jax.tree.map(
+            lambda s, p: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=getattr(p, "sharding", None)),
+            specs, params)
+        return self.core.lower(specs, state, tok, pos)
 
 
 def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
@@ -122,10 +173,14 @@ def serve_batch(cfg, *, batch: int, prompt_len: int, gen: int,
     if not quiet:
         print(f"[serve] batch={batch} compile {t_compile:.2f}s | prefill "
               f"{prompt_len} tok in {t_prefill:.2f}s | decode {gen} tok in "
-              f"{t_decode:.2f}s ({tput:.1f} tok/s)")
+              f"{t_decode:.2f}s ({tput:.1f} tok/s) | weights prepared "
+              f"{decode_fn.weights_prepared}, reused "
+              f"{decode_fn.weights_reused}")
     return {"tokens": out_tokens, "prompts": prompts, "logits": kept,
             "decode_tok_per_s": tput, "compile_s": t_compile,
-            "prefill_s": t_prefill, "decode_s": t_decode}
+            "prefill_s": t_prefill, "decode_s": t_decode,
+            "weights_prepared": decode_fn.weights_prepared,
+            "weights_reused": decode_fn.weights_reused}
 
 
 def serve_distributed(*, ranks: int = 3, sessions: int = 32,

@@ -55,6 +55,14 @@ def cast(w, dtype):
         return w.astype(dtype)
 
 
+# The parameter leaves (by key) that the serving step reads only as a cast
+# to the compute dtype (``cast``), so a copy held in that dtype computes
+# the same numbers (``lm.serving_params``). Norm gains are read in f32 and
+# are not among them.
+CAST_LEAVES = frozenset({"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                         "router", "embed", "head"})
+
+
 def dense_init(key, shape, scale: float | None = None, dtype=jnp.float32):
     fan_in = shape[0]
     scale = scale if scale is not None else 1.0 / math.sqrt(fan_in)

@@ -341,6 +341,32 @@ def decode_step(params: Params, cfg: ModelConfig, state, batch, pos, *,
         return logits[..., : cfg.vocab_size], new_state
 
 
+def serving_params(cfg: ModelConfig, params: Params) -> Params:
+    """``params`` as the serving step can hold them: each leaf of
+    ``blocks.CAST_LEAVES`` in the compute dtype, cast once by one jitted
+    program in a ``cast`` scope, under a ``prepare_weights`` host span;
+    every other leaf is the caller's own array. Where no such leaf differs
+    from the compute dtype, ``params`` itself is returned."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    leaves = [w for _, w in flat]
+    picked = [i for i, (path, w) in enumerate(flat)
+              if isinstance(path[-1], jax.tree_util.DictKey)
+              and path[-1].key in B.CAST_LEAVES and w.dtype != cdt]
+    if not picked:
+        return params
+    with jax.profiler.TraceAnnotation("prepare_weights"):
+        cast = _cast_weights(cdt, [leaves[i] for i in picked])
+    for i, w in zip(picked, cast):
+        leaves[i] = w
+    return treedef.unflatten(leaves)
+
+
+@partial(jax.jit, static_argnums=0)
+def _cast_weights(dtype, weights):
+    return [B.cast(w, dtype) for w in weights]
+
+
 def prefill(params: Params, cfg: ModelConfig, batch, *, dist=None):
     """Full-sequence prefill returning last-position logits.
 

@@ -29,13 +29,14 @@ def _cfg():
 
 
 def _step_hlo(cfg) -> str:
-    """The optimized HLO of the serving step: 32 slots of 16 positions,
-    two decode groups of 16 tokens."""
+    """The optimized HLO of the serving step's unprepared core, handed the
+    f32 weights so that it casts them: 32 slots of 16 positions, two
+    decode groups of 16 tokens."""
     params = jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0)))
     state = lm.decode_state_specs(cfg, 32, 16)
     tok = jax.ShapeDtypeStruct((32, 1), jnp.int32)
     pos = jax.ShapeDtypeStruct((32,), jnp.int32)
-    return serve.make_decode_fn(cfg).lower(
+    return serve.make_decode_fn(cfg).core.lower(
         params, state, tok, pos).compile().as_text()
 
 
@@ -196,4 +197,5 @@ def test_serve_batch_emits_its_spans(monkeypatch):
     cfg = get_config("smollm-135m").reduced()
     serve.serve_batch(cfg, batch=2, prompt_len=3, gen=2, quiet=True)
     admit, prefill, decode, read = serve.SPANS
-    assert names == [admit, prefill] + [read, decode] * 2
+    # the weights' one copy is made in the first call, before the prefill
+    assert names == [admit, "prepare_weights", prefill] + [read, decode] * 2

@@ -84,9 +84,10 @@ GRANITE_SLOTS, GRANITE_SMAX = 128, 256
 
 
 @pytest.fixture(scope="module")
-def granite_step(one_chip):
+def granite_programs(one_chip):
     """granite-moe-1b-a400m's serving step at 128 slots of 256, compiled
-    for the described chip: (config, optimized HLO text)."""
+    for the described chip from f32 parameter specs: (config, the served
+    step's optimized HLO text, its unprepared core's)."""
     import dataclasses
 
     from repro.configs import get_config
@@ -108,23 +109,31 @@ def granite_step(one_chip):
                                sharding=one_chip)
     pos = jax.ShapeDtypeStruct((GRANITE_SLOTS,), jnp.int32,
                                sharding=one_chip)
-    text = make_decode_fn(cfg).lower(params, state, tok, pos).compile(
-        ).as_text()
-    return cfg, text
+    step = make_decode_fn(cfg)
+    served, core = (fn(params, state, tok, pos).compile().as_text()
+                    for fn in (step.lower, step.core.lower))
+    return cfg, served, core
 
 
-def test_granite_decode_step_scopes(granite_step):
+@pytest.fixture(scope="module")
+def granite_step(granite_programs):
+    """The served step: (config, optimized HLO text)."""
+    cfg, served, _ = granite_programs
+    return cfg, served
+
+
+def test_granite_decode_step_scopes(granite_programs):
     """granite-moe-1b-a400m's serving step at 128 slots of 256, as the
-    chip's compiler leaves it: XLA hoists each weight's f32 -> bf16 cast
-    out of the layer loop without its metadata, and ``op_scopes`` still
-    reads the expert weights' casts as ``cast``; every matmul and every
-    sublayer has its scope."""
+    chip's compiler leaves its unprepared core handed f32 weights: XLA
+    hoists each weight's f32 -> bf16 cast out of the layer loop without
+    its metadata, and ``op_scopes`` still reads the expert weights' casts
+    as ``cast``; every matmul and every sublayer has its scope."""
     import re
 
     from repro.analysis import hlo as H
     from repro.models import blocks
 
-    _, text = granite_step
+    _, _, text = granite_programs
     scopes = H.op_scopes(text)
     comps = H._split_computations(text)
     entry = next(c for c in comps.values() if c.is_entry)
@@ -174,3 +183,66 @@ def test_granite_decode_attention_reads_cache_in_place(granite_step):
                 assert n not in (repeated, cfg.n_layers * repeated), (
                     i.name, i.shape)
     assert seen
+
+
+def _weight_passes(text):
+    """The passes over a whole weight of ``blocks.CAST_LEAVES`` in the
+    entry computation: each conversion, copy or transpose (alone or as a
+    fusion's root) that reads one, directly or through another such pass,
+    and writes as many elements, as (name, shape)."""
+    import re
+
+    from repro.analysis import hlo as H
+    from repro.models import blocks
+
+    comps = H._split_computations(text)
+    entry = next(c for c in comps.values() if c.is_entry)
+    ops = {i.name: i for i in entry.instrs}
+    passes = {"convert", "copy", "copy-start", "copy-done", "transpose",
+              "bitcast"}
+
+    def size(i):
+        dims = re.search(r"\[([\d,]*)\]", i.shape).group(1)
+        return math.prod(int(d) for d in dims.split(",") if d)
+
+    def kind(i):
+        m = re.search(r"calls=%?([\w.\-]+)", i.line)
+        if i.op != "fusion" or not m:
+            return i.op
+        return next(j.op for j in comps[m.group(1)].instrs
+                    if j.line.lstrip().startswith("ROOT"))
+
+    def weight(i):
+        """Whether ``i`` is a whole weight, or a pass over one."""
+        if i.op == "parameter":
+            m = re.search(r'op_name="params[^"]*\[\\?\'(\w+)\\?\'\]"',
+                          i.line)
+            return m is not None and m.group(1) in blocks.CAST_LEAVES
+        src = ops.get(i.operands[0]) if i.operands else None
+        return (kind(i) in passes and src is not None
+                and size(i) == size(src) and weight(src))
+
+    return [(i.name, i.shape) for i in entry.instrs
+            if kind(i) in passes - {"bitcast", "copy-done"}
+            and weight(i)]
+
+
+def test_granite_served_step_casts_no_weight(granite_programs):
+    """The served step is handed its weights in bf16 (``make_decode_fn``
+    -> ``lm.serving_params``), so the chip's compiler leaves no op that
+    ``op_scopes`` reads as ``cast``, no conversion of a whole weight and
+    no copy or transpose of one; the core handed f32 weights has them
+    (the nine converts and the weight prefetches), so the check sees
+    what it looks for. Every sublayer keeps its scope."""
+    from repro.analysis import hlo as H
+    from repro.models import blocks
+
+    _, served, core = granite_programs
+    scopes = H.op_scopes(served)
+    assert [n for n, sc in scopes.items() if sc == blocks.CAST] == []
+    assert _weight_passes(served) == []
+    assert set(blocks.SCOPES) <= set(scopes.values())
+    found = _weight_passes(core)
+    assert sum(n.startswith("convert") for n, _ in found) == 9
+    assert any(n.startswith("copy-start") for n, _ in found)
+    assert blocks.CAST in H.op_scopes(core).values()
